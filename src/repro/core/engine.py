@@ -144,7 +144,7 @@ class DeferredDivergence:
                               round=self.round_id):
                     self._value = float(self._raw)
                 block_us = (time.perf_counter_ns() - t0) / 1e3
-                rec.hist("engine.close_block_us").observe(block_us)
+                rec.host_sync("divergence", 1, self.round_id)
                 if self.round_id is not None:
                     rec.round_set(self.round_id,
                                   close_block_us=round(block_us, 1),
@@ -594,7 +594,8 @@ class RoundBuffers:
                 next_chunk=0, num_chunks=num_chunks, expected=expected,
                 filled=[0] * num_chunks, eager_folds=0)
         else:
-            entry["stacks"] = self._alloc()
+            with self.rec.span("ring.alloc", cat="ring", round=round_id):
+                entry["stacks"] = self._alloc()
         self._open[round_id] = entry
         if self.rec.enabled:
             self.rec.event("ring.begin", cat="ring", round=round_id,
@@ -712,7 +713,12 @@ class RoundBuffers:
                 e["filled"][k] += 1
             else:
                 leaves = {p: flat[p] for p in self._shapes}
-                e["stacks"] = _scatter(e["stacks"], jnp.int32(slot), leaves)
+                # the scatter dispatch, apart from the host work around it;
+                # ``open`` is the ring's open rounds, the sets it may wait on
+                with self.rec.span("ring.scatter", cat="ring", round=round_id,
+                                   open=len(self._open)):
+                    e["stacks"] = _scatter(e["stacks"], jnp.int32(slot),
+                                           leaves)
         e["written"][client_id] = slot
         if e["chunked"]:
             self._cascade(round_id, e)
@@ -1569,7 +1575,6 @@ class RoundCloseEngine:
         compiled = self._close._cache_size() > before
         rec.counter(f"engine.compile_{'miss' if compiled else 'hit'}"
                     f"[{sig}]").inc()
-        rec.hist("engine.close_dispatch_us").observe(dispatch_us)
         if round_id is not None:
             rec.round_set(round_id, method=self.method,
                           close_dispatch_us=round(dispatch_us, 1),
@@ -1627,10 +1632,8 @@ class RoundCloseEngine:
     def _finish_peak(self, round_id) -> int:
         peak = self._peak.pop(round_id, 0)
         self.last_peak_bytes = peak
-        if self.rec.enabled:
-            self.rec.gauge("close.peak_bytes").set(peak)
-            if round_id is not None:
-                self.rec.round_set(round_id, peak_bytes=peak)
+        if self.rec.enabled and round_id is not None:
+            self.rec.round_set(round_id, peak_bytes=peak)
         return peak
 
     def peak_close_bytes(self, round_id) -> int:
@@ -2104,7 +2107,6 @@ class RoundCloseEngine:
         if not rec.enabled:
             return
         dispatch_us = (time.perf_counter_ns() - t0) / 1e3
-        rec.hist("engine.close_dispatch_us").observe(dispatch_us)
         if round_id is not None:
             rec.round_set(round_id, method=self.method, chunked=1,
                           close_dispatch_us=round(dispatch_us, 1),

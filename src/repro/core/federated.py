@@ -40,6 +40,7 @@ from repro.configs.base import FedConfig, LoRAConfig, TrainConfig
 from repro.core import aggregation as agg
 from repro.core.divergence import mean_deviation
 from repro.core.lora import init_lora
+from repro.obs import NULL, NULL_SPAN
 from repro.optim import adamw_update, clip_by_global_norm, init_adamw, lr_at
 from repro.util.logging import get_logger
 
@@ -93,17 +94,22 @@ class RoundRecord:
     lr: float
 
 
-def evaluate_on_batches(eval_fn, params, lora,
-                        batches) -> tuple[float, float]:
+def evaluate_on_batches(eval_fn, params, lora, batches, recorder=NULL,
+                        round_id=None) -> tuple[float, float]:
     """Mean (loss, accuracy) of ``eval_fn`` over ``batches`` (NaNs when
-    empty). Shared by the host and mesh trainers."""
+    empty). Shared by the host and mesh trainers; ``recorder`` spans each
+    batch's dispatch and its two-scalar host sync."""
     if not batches:
         return float("nan"), float("nan")
     ls, accs = [], []
     for b in batches:
-        l, a = eval_fn(params, lora, b)
-        ls.append(float(l))
-        accs.append(float(a))
+        with recorder.span("eval.dispatch", cat="trainer"):
+            l, a = eval_fn(params, lora, b)
+        with recorder.span("eval.sync", cat="trainer"):
+            ls.append(float(l))
+            accs.append(float(a))
+        if recorder.enabled:
+            recorder.host_sync("eval", 2, round_id)
     return sum(ls) / len(ls), sum(accs) / len(accs)
 
 
@@ -422,9 +428,11 @@ class FederatedTrainer:
                       uplink=recon["uplink"], downlink=recon["downlink"])
 
     # ------------------------------------------------------------------
-    def _client_round(self, client: int, params, lora):
+    def _client_round(self, client: int, params, lora, rnd: int):
+        rec = self.recorder
         loader = self.client_loaders[client % len(self.client_loaders)]
-        opt_state = init_adamw(lora)
+        with rec.span("client.opt_init", cat="trainer"):
+            opt_state = init_adamw(lora)
         losses = []
         # uneven budgets: client c stops after its own step count (mesh mode
         # expresses the same schedule as masked scan iterations)
@@ -432,19 +440,29 @@ class FederatedTrainer:
                  if self.fed_cfg.client_local_steps
                  else self.fed_cfg.local_steps)
         for s in range(steps):
-            batch = loader.next_batch()
-            lr = lr_at(self._global_step + s, base_lr=self.train_cfg.learning_rate,
-                       total_steps=self._total_steps,
-                       warmup_ratio=self.train_cfg.warmup_ratio,
-                       kind=self.train_cfg.schedule)
-            lora, opt_state, loss, gnorm = self.local_step(params, lora, opt_state,
-                                                           batch, lr)
-            losses.append(float(loss))
+            with (rec.span("client.step", cat="trainer", round=rnd,
+                           client=client, step=s)
+                  if rec.tracing else NULL_SPAN):
+                with rec.span("step.batch", cat="trainer"):
+                    batch = loader.next_batch()
+                with rec.span("step.lr", cat="trainer"):
+                    lr = lr_at(self._global_step + s,
+                               base_lr=self.train_cfg.learning_rate,
+                               total_steps=self._total_steps,
+                               warmup_ratio=self.train_cfg.warmup_ratio,
+                               kind=self.train_cfg.schedule)
+                with rec.span("step.dispatch", cat="trainer"):
+                    lora, opt_state, loss, gnorm = self.local_step(
+                        params, lora, opt_state, batch, lr)
+                with rec.span("step.sync", cat="trainer"):
+                    losses.append(float(loss))
+            if rec.enabled:
+                rec.host_sync("step", 1, rnd)
         return lora, losses
 
-    def _evaluate(self, params, lora) -> tuple[float, float]:
+    def _evaluate(self, params, lora, rnd: int) -> tuple[float, float]:
         return evaluate_on_batches(self.eval_fn, params, lora,
-                                   self.eval_batches)
+                                   self.eval_batches, self.recorder, rnd)
 
     def _resolve_divergences(self) -> None:
         resolve_divergences(self.history)
@@ -461,10 +479,14 @@ class FederatedTrainer:
         from repro.core.engine import DeferredDivergence
 
         for rnd in range(self._start_round, stop):
-            lr_now = float(lr_at(self._global_step, base_lr=self.train_cfg.learning_rate,
-                                 total_steps=self._total_steps,
-                                 kind=self.train_cfg.schedule,
-                                 warmup_ratio=self.train_cfg.warmup_ratio))
+            with self.recorder.span("round.lr", cat="trainer", round=rnd):
+                lr_now = float(lr_at(self._global_step,
+                                     base_lr=self.train_cfg.learning_rate,
+                                     total_steps=self._total_steps,
+                                     kind=self.train_cfg.schedule,
+                                     warmup_ratio=self.train_cfg.warmup_ratio))
+            if self.recorder.enabled:
+                self.recorder.host_sync("schedule", 1, rnd)
 
             if self.hetero and self.engine is not None:
                 from repro.core.hetero import pad_adapters
@@ -488,7 +510,7 @@ class FederatedTrainer:
                     self.coordinator._ensure_spec(self.global_lora)
                 for c in range(k):
                     lora_c, losses = self._client_round(
-                        c, self.client_params[c], self._client_lora[c])
+                        c, self.client_params[c], self._client_lora[c], rnd)
                     client_losses.append(losses[-1])
                     padded = pad_adapters(lora_c, self.lora_cfg.rank)
                     if self.fault_injector is None:
@@ -532,7 +554,7 @@ class FederatedTrainer:
                 client_losses = []
                 for c in range(k):
                     lora_c, losses = self._client_round(
-                        c, self.client_params[c], self._client_lora[c])
+                        c, self.client_params[c], self._client_lora[c], rnd)
                     client_loras.append(lora_c)
                     client_losses.append(losses[-1])
                 new_loras, residuals = hetero_fedex_aggregate(
@@ -553,7 +575,8 @@ class FederatedTrainer:
                                     jax.tree.leaves(mean_prod))))
             elif self.method == "centralized":
                 # single worker sees every client's stream round-robin
-                lora, losses = self._client_round(rnd % k, self.params, self.global_lora)
+                lora, losses = self._client_round(rnd % k, self.params,
+                                                  self.global_lora, rnd)
                 self.global_lora = lora
                 div = 0.0
                 client_losses = [losses[-1]]
@@ -569,7 +592,8 @@ class FederatedTrainer:
                     base = (self.client_params[c]
                             if self.client_params is not None else self.params)
                     start = self._client_lora[c] if keep_local else start_lora
-                    lora_c, losses = self._client_round(c, base, start)
+                    lora_c, losses = self._client_round(c, base, start,
+                                                        round_id)
                     if self.fed_cfg.dp_clip > 0:
                         from repro.core.privacy import privatize_upload
                         lora_c = privatize_upload(
@@ -631,7 +655,7 @@ class FederatedTrainer:
                          else self.global_lora)
             with self.recorder.span("round.eval", cat="trainer", round=rnd,
                                     batches=len(self.eval_batches)):
-                ev_loss, ev_acc = self._evaluate(eval_params, eval_lora)
+                ev_loss, ev_acc = self._evaluate(eval_params, eval_lora, rnd)
             if self.recorder.enabled:
                 self.recorder.round_set(rnd, eval_loss=round(ev_loss, 6),
                                         eval_acc=round(ev_acc, 6))

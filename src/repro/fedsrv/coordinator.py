@@ -25,6 +25,7 @@ gets aggregated). A BytesLedger entry is recorded per payload.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -258,60 +259,75 @@ class RoundCoordinator:
         ``rank`` declares a ragged (hetero) uplink's true LoRA rank: it
         rides the payload header so rank-aware validation applies and the
         ring's slot rank vector records it at ingest.
+
+        Obs: the ``client.uplink`` span, and its wall time summed into the
+        round record's ``uplink_ms``.
         """
-        with self.rec.span("client.uplink", cat="fedsrv", round=round_id,
-                           client=client_id):
-            payload = self.codec.encode(lora, round_id=round_id,
-                                        client_id=client_id,
-                                        direction="uplink", rank=rank)
-            kinds: List[str] = []
-            if self.faults is not None:
-                payload, applied = self.faults.corrupt(payload)
-                kinds = [s.kind for s in applied]
-            if "crash" in kinds:
-                # client died mid-uplink: nothing ever reaches the server
-                self.ledger.record(payload, note="fault:crash",
-                                   direction="dropped")
-                self._note_undelivered(round_id, client_id, "crash",
-                                       "dropped")
-                return UplinkResult(ok=False, reason="crash",
-                                    status="dropped")
-            if payload.round_id != round_id and self.sink is None:
-                # replayed/misaddressed uplink with no ring to refuse it —
-                # the coordinator rejects the address itself
-                self.ledger.record(payload, note="drop:replay",
-                                   direction="dropped")
-                self._note_undelivered(round_id, client_id, "replay",
-                                       "dropped")
-                return UplinkResult(ok=False, reason="replay",
-                                    status="dropped")
+        rec = self.rec
+        if not rec.enabled:
+            return self._send(lora, round_id, client_id, weight, rank)
+        t0 = time.perf_counter_ns()
+        with rec.span("client.uplink", cat="fedsrv", round=round_id,
+                      client=client_id):
+            res = self._send(lora, round_id, client_id, weight, rank)
+        rec.round_inc(round_id, "uplink_ms",
+                      (time.perf_counter_ns() - t0) / 1e6)
+        return res
+
+    def _send(self, lora: Any, round_id: int, client_id: int, weight: float,
+              rank: Optional[int]) -> UplinkResult:
+        """The body of :meth:`_uplink`: encode, corrupt, deliver, ledger."""
+        payload = self.codec.encode(lora, round_id=round_id,
+                                    client_id=client_id,
+                                    direction="uplink", rank=rank)
+        kinds: List[str] = []
+        if self.faults is not None:
+            payload, applied = self.faults.corrupt(payload)
+            kinds = [s.kind for s in applied]
+        if "crash" in kinds:
+            # client died mid-uplink: nothing ever reaches the server
+            self.ledger.record(payload, note="fault:crash",
+                               direction="dropped")
+            self._note_undelivered(round_id, client_id, "crash",
+                                   "dropped")
+            return UplinkResult(ok=False, reason="crash",
+                                status="dropped")
+        if payload.round_id != round_id and self.sink is None:
+            # replayed/misaddressed uplink with no ring to refuse it —
+            # the coordinator rejects the address itself
+            self.ledger.record(payload, note="drop:replay",
+                               direction="dropped")
+            self._note_undelivered(round_id, client_id, "replay",
+                                   "dropped")
+            return UplinkResult(ok=False, reason="replay",
+                                status="dropped")
+        try:
+            tree, retries = self._deliver(payload, weight)
+        except StaleUplinkError as e:
+            self.ledger.record(payload, note=f"drop:{e.reason}",
+                               direction="dropped")
+            self._note_undelivered(round_id, client_id, e.reason,
+                                   "dropped")
+            return UplinkResult(ok=False, reason=e.reason,
+                                status="dropped")
+        except TransportError as e:
+            self.ledger.record(payload, note=f"quarantine:{e.reason}",
+                               direction="quarantined")
+            self._note_undelivered(round_id, client_id, e.reason,
+                                   "quarantined")
+            return UplinkResult(ok=False, reason=e.reason,
+                                status="quarantined")
+        self.ledger.record(payload)
+        if "duplicate" in kinds:
+            # the duplicate copy consumed wire bytes but the ring drops
+            # its lane write — record it, expect the StaleUplinkError
             try:
-                tree, retries = self._deliver(payload, weight)
-            except StaleUplinkError as e:
-                self.ledger.record(payload, note=f"drop:{e.reason}",
-                                   direction="dropped")
-                self._note_undelivered(round_id, client_id, e.reason,
-                                       "dropped")
-                return UplinkResult(ok=False, reason=e.reason,
-                                    status="dropped")
-            except TransportError as e:
-                self.ledger.record(payload, note=f"quarantine:{e.reason}",
-                                   direction="quarantined")
-                self._note_undelivered(round_id, client_id, e.reason,
-                                       "quarantined")
-                return UplinkResult(ok=False, reason=e.reason,
-                                    status="quarantined")
-            self.ledger.record(payload)
-            if "duplicate" in kinds:
-                # the duplicate copy consumed wire bytes but the ring drops
-                # its lane write — record it, expect the StaleUplinkError
-                try:
-                    self._deliver(payload)
-                except StaleUplinkError:
-                    pass
-                self.ledger.record(payload, note="fault:duplicate",
-                                   direction="dropped")
-            return UplinkResult(ok=True, tree=tree, retries=retries)
+                self._deliver(payload)
+            except StaleUplinkError:
+                pass
+            self.ledger.record(payload, note="fault:duplicate",
+                               direction="dropped")
+        return UplinkResult(ok=True, tree=tree, retries=retries)
 
     def _note_undelivered(self, round_id: int, client_id: int, reason: str,
                           status: str) -> None:

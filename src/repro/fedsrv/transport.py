@@ -32,6 +32,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.obs import NULL
@@ -188,14 +189,20 @@ class AdapterCodec:
         codec = self.quantize if direction == "uplink" else "none"
         with self.rec.span("codec.encode", cat="transport", round=round_id,
                            client=client_id, codec=codec):
+            flat = flatten_with_paths(tree)
             tensors = {path: self._encode_leaf(leaf, codec)
-                       for path, leaf in flatten_with_paths(tree).items()}
+                       for path, leaf in flat.items()}
         payload = Payload(round_id=round_id, client_id=client_id,
                           direction=direction, codec=codec, tensors=tensors,
                           rank=None if rank is None else int(rank))
         if self.rec.enabled:
             self.rec.counter(f"transport.{direction}_bytes").inc(payload.nbytes)
             self.rec.counter(f"transport.{direction}_payloads").inc()
+            if direction == "uplink":
+                # each device leaf was one blocking fetch to the host
+                fetched = sum(isinstance(x, jax.Array) for x in flat.values())
+                if fetched:
+                    self.rec.host_sync("uplink", fetched, round_id)
         return payload
 
     def _decode_flat(self, payload: Payload) -> Dict[str, np.ndarray]:

@@ -69,6 +69,7 @@ def lora_factor_mean(stack: jnp.ndarray, weights: jnp.ndarray | None = None, *,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
             interpret=interpret,
+            name="factor_mean",
         )(xp)[:m, :n]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -82,4 +83,5 @@ def lora_factor_mean(stack: jnp.ndarray, weights: jnp.ndarray | None = None, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="factor_mean_weighted",
     )(weights.astype(jnp.float32), xp)[:m, :n]

@@ -158,6 +158,7 @@ def fedex_residual_apply(w0: jnp.ndarray, a_stack: jnp.ndarray,
             out_shape=jax.ShapeDtypeStruct((mp, np_), w0p.dtype),
             input_output_aliases={0: 0},
             interpret=interpret,
+            name="fedex_residual",
         )(w0p, ap, bp)[:m, :n]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -176,6 +177,7 @@ def fedex_residual_apply(w0: jnp.ndarray, a_stack: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((mp, np_), w0p.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="fedex_residual_weighted",
     )(weights.astype(jnp.float32), w0p, ap, bp)[:m, :n]
 
 
@@ -229,6 +231,7 @@ def product_fold_apply(w0: jnp.ndarray, a_stack: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((mp, np_), w0p.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="product_fold",
     )(signs.astype(jnp.float32), w0p, ap, bp)[:m, :n]
 
 
@@ -272,6 +275,7 @@ def product_accum_apply(acc: jnp.ndarray, a_stack: jnp.ndarray,
         # vector): alias it to the output for the in-place update
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="product_accum",
     )(signs.astype(jnp.float32), accp, ap, bp)[:m, :n]
 
 
@@ -329,6 +333,7 @@ def perclient_fold_apply(w0_stack: jnp.ndarray, a_stack: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((c, mp, np_), w0p.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="perclient_fold",
     )(weights.astype(jnp.float32), w0p, ap, bp)[:, :m, :n]
 
 
@@ -409,5 +414,6 @@ def hetero_fold_apply(w0_stack: jnp.ndarray, a_stack: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((c, mp, np_), w0p.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="hetero_fold",
     )(weights.astype(jnp.float32), ranks.astype(jnp.int32), w0p, ap, bp,
       oap, obp)[:, :m, :n]

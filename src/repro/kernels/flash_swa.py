@@ -106,4 +106,5 @@ def flash_swa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_swa",
     )(q, k, v)

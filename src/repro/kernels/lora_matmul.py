@@ -79,4 +79,5 @@ def lora_matmul(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, r), jnp.float32)],
         interpret=interpret,
+        name="lora_matmul",
     )(x, w, a, b)[:m, :n]

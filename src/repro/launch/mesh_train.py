@@ -253,7 +253,6 @@ class MeshRoundCloser:
             sig = f"mesh:{self.method}"
             rec.counter(
                 f"engine.compile_{'miss' if compiled else 'hit'}[{sig}]").inc()
-            rec.hist("engine.close_dispatch_us").observe(dispatch_us)
             if round_id is not None:
                 rec.round_set(round_id, method=self.method,
                               close_dispatch_us=round(dispatch_us, 1),
@@ -415,6 +414,8 @@ class MeshFederatedTrainer:
         to exact zero. Returns (stacks', survivors, weights', quarantined)."""
         fc = self.fed_cfg
         host = {p: np.array(x) for p, x in stacks.items()}
+        if self.recorder.enabled:
+            self.recorder.host_sync("uplink", len(host), rnd)
         survivors: List[int] = []
         surv_w: List[float] = []
         quarantined: List[Tuple[int, str]] = []
@@ -513,7 +514,7 @@ class MeshFederatedTrainer:
             step0 += fc.local_steps
             with self.recorder.span("round.eval", cat="trainer", round=rnd,
                                     batches=len(self.eval_batches)):
-                ev_loss, ev_acc = self._evaluate()
+                ev_loss, ev_acc = self._evaluate(rnd)
             if self.recorder.enabled:
                 self.recorder.round_set(rnd, sampled=n_sampled,
                                         delivered=len(ids),
@@ -532,6 +533,9 @@ class MeshFederatedTrainer:
                                           or [float("nan")]),
                 eval_loss=ev_loss, eval_acc=ev_acc, divergence_scaled=div,
                 lr=float(lrs[0]))
+            if self.recorder.enabled:
+                self.recorder.host_sync("step", 1, rnd)
+                self.recorder.host_sync("schedule", 1, rnd)
             self.history.append(rec)
             logger.info(
                 "round=%d mode=mesh sampled=%d/%d delivered=%d "
@@ -541,6 +545,7 @@ class MeshFederatedTrainer:
         self._resolve_divergences()
         return self.history
 
-    def _evaluate(self) -> Tuple[float, float]:
+    def _evaluate(self, rnd: int) -> Tuple[float, float]:
         return evaluate_on_batches(self.eval_fn, self.params,
-                                   self.global_lora, self.eval_batches)
+                                   self.global_lora, self.eval_batches,
+                                   self.recorder, rnd)

@@ -34,8 +34,8 @@ uplinks while the previous close is in flight.
 """
 
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.recorder import (NULL, OBS_MODES, NullRecorder, Recorder,
-                                make_recorder)
+from repro.obs.recorder import (NULL, NULL_SPAN, OBS_MODES, NullRecorder,
+                                Recorder, make_recorder)
 from repro.obs.tracer import Span, Tracer
 
 __all__ = [
@@ -44,6 +44,7 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "NULL",
+    "NULL_SPAN",
     "NullRecorder",
     "OBS_MODES",
     "Recorder",

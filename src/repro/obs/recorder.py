@@ -16,9 +16,15 @@ sweeps), so round 0 of scenario 2 never merges into round 0 of scenario 1.
 
 The JSONL metrics stream (``write_metrics``) is the contract consumed by
 ``scripts/obs_report.py``: one JSON object per line with a ``type`` field —
-``meta`` (jax/device info), ``counters`` (the registry snapshot), ``round``
-(one per (run, round)), ``span`` / ``event`` (trace mode only, timestamps in
-µs relative to the tracer origin).
+``meta`` (jax/device info; in trace mode also the tracer's
+``origin_unix_ns``), ``counters`` (the registry snapshot), ``round`` (one per
+(run, round)), ``span`` / ``event`` (trace mode only, timestamps in µs
+relative to the tracer origin).
+
+Host syncs: every place the trainer blocks on a device value calls
+``host_sync(site, n, round_id)``, which bumps the ``host.syncs[<site>]``
+counter and the round record's ``host_syncs`` total. Callers guard it on
+``enabled`` like every other increment.
 """
 
 from __future__ import annotations
@@ -62,7 +68,7 @@ class _NullMetric:
         pass
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 _NULL_METRIC = _NullMetric()
 
 
@@ -80,7 +86,7 @@ class NullRecorder:
         pass
 
     def span(self, name: str, cat: str = "host", **args) -> _NullSpan:
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def event(self, name: str, cat: str = "host", **args) -> None:
         pass
@@ -98,6 +104,9 @@ class NullRecorder:
         pass
 
     def round_inc(self, round_id, key: str, n=1) -> None:
+        pass
+
+    def host_sync(self, site: str, n: int = 1, round_id=None) -> None:
         pass
 
     def round_records(self) -> List[Dict[str, Any]]:
@@ -141,7 +150,7 @@ class Recorder:
     def span(self, name: str, cat: str = "host", **args):
         if self.tracer is not None:
             return self.tracer.span(name, cat, run=self.run, **args)
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def event(self, name: str, cat: str = "host", **args) -> None:
         if self.tracer is not None:
@@ -172,6 +181,14 @@ class Recorder:
         rec = self._round(round_id)
         rec[key] = rec.get(key, 0) + n
 
+    def host_sync(self, site: str, n: int = 1, round_id=None) -> None:
+        """Count ``n`` blocking device→host reads at ``site`` (``step``,
+        ``eval``, ``schedule``, ``uplink``, ``divergence``), and add them
+        to round ``round_id``'s ``host_syncs`` when it is given."""
+        self.metrics.counter(f"host.syncs[{site}]").inc(n)
+        if round_id is not None:
+            self.round_inc(round_id, "host_syncs", n)
+
     def round_records(self) -> List[Dict[str, Any]]:
         return [dict(rec) for rec in self._rounds.values()]
 
@@ -185,8 +202,11 @@ class Recorder:
     def metrics_records(self) -> List[Dict[str, Any]]:
         """Every JSONL record, in stream order (meta, counters, rounds,
         then spans/events when tracing)."""
+        meta = {"type": "meta", "mode": self.mode, **_env_meta()}
+        if self.tracer is not None:
+            meta["origin_unix_ns"] = self.tracer.origin_unix_ns
         out: List[Dict[str, Any]] = [
-            {"type": "meta", "mode": self.mode, **_env_meta()},
+            meta,
             {"type": "counters", **self.metrics.snapshot()},
         ]
         for rec in self._rounds.values():

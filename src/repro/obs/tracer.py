@@ -19,6 +19,15 @@ Export targets:
   timestamps in µs relative to the tracer's origin — the stream
   ``scripts/obs_report.py`` summarizes and checks the overlap invariant on.
 
+One clock with the device trace: the tracer's origin is taken twice, as
+``perf_counter_ns`` (what span timestamps are relative to) and as
+``origin_unix_ns`` (``time.time_ns`` read back to back with it). Both exports
+carry ``origin_unix_ns``, so a span's wall-clock time is
+``origin_unix_ns + ts``. A ``jax.profiler`` capture (``.xplane.pb``) stores
+host events relative to its ``profile_start_time`` (a stat of the
+``Task Environment`` plane, in epoch ns), so the same instant sits at
+``origin_unix_ns + ts - profile_start_time`` in the capture.
+
 No external dependencies; everything is stdlib + an optional jax import.
 """
 
@@ -27,12 +36,25 @@ from __future__ import annotations
 import json
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 try:  # device-side annotation (present in every supported jax)
     from jax.profiler import TraceAnnotation as _TraceAnnotation
 except Exception:  # pragma: no cover - jax always present in this repo
     _TraceAnnotation = None
+
+
+def _paired_origin(tries: int = 5) -> Tuple[int, int]:
+    """(perf_counter_ns, time_ns) read as one instant: of a few back-to-back
+    reads, the pair whose bracketing perf_counter reads lie closest."""
+    best = None
+    for _ in range(tries):
+        a = time.perf_counter_ns()
+        unix = time.time_ns()
+        b = time.perf_counter_ns()
+        if best is None or b - a < best[0]:
+            best = (b - a, (a + b) // 2, unix)
+    return best[1], best[2]
 
 
 class Span:
@@ -79,7 +101,7 @@ class Tracer:
 
     def __init__(self, device_annotations: bool = False):
         self.device_annotations = device_annotations
-        self._t0 = time.perf_counter_ns()
+        self._t0, self.origin_unix_ns = _paired_origin()
         # recorded span dicts: name/cat/run/ts/dur (ns)/tid/args
         self.spans: List[Dict[str, Any]] = []
         # instant event dicts: name/cat/run/ts (ns)/tid/args
@@ -153,7 +175,8 @@ class Tracer:
                 "name": e["name"], "cat": e["cat"], "ph": "i", "s": "t",
                 "ts": e["ts"] / 1e3, "pid": 0, "tid": e["tid"], "args": args,
             })
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
+        return {"traceEvents": events, "displayTimeUnit": "ms",
+                "otherData": {"origin_unix_ns": self.origin_unix_ns}}
 
     def write_chrome_trace(self, path: str,
                            process_name: str = "repro") -> None:
