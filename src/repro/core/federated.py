@@ -158,6 +158,8 @@ class FederatedTrainer:
         freeze = self.method == "ffa"
         self.local_step = make_local_step(self.model, self.scale, self.train_cfg,
                                           freeze_a=freeze)
+        # step.saved_residual_bytes, reckoned at the first step's shapes
+        self._residuals_gauged = False
         self.eval_fn = make_eval_fn(self.model, self.scale)
         self.history: List[RoundRecord] = []
         # fedsrv RoundOutcome per standard round; adapter payloads are kept
@@ -451,6 +453,8 @@ class FederatedTrainer:
                                total_steps=self._total_steps,
                                warmup_ratio=self.train_cfg.warmup_ratio,
                                kind=self.train_cfg.schedule)
+                if not self._residuals_gauged:
+                    self._gauge_saved_residuals(params, lora, batch, rnd)
                 with rec.span("step.dispatch", cat="trainer"):
                     lora, opt_state, loss, gnorm = self.local_step(
                         params, lora, opt_state, batch, lr)
@@ -459,6 +463,22 @@ class FederatedTrainer:
             if rec.enabled:
                 rec.host_sync("step", 1, rnd)
         return lora, losses
+
+    def _gauge_saved_residuals(self, params, lora, batch, rnd: int) -> None:
+        """Record the bytes the step's train scan keeps for the backward
+        instead of recomputing them (0 under full remat): read from the
+        loss's traced program at the step's shapes, so it is the decision the
+        compiled step makes. Traced in every obs mode, so a recorded run
+        traces and compiles exactly what an unrecorded one does."""
+        from repro.models.transformer import saved_residual_bytes
+
+        jaxpr = jax.make_jaxpr(
+            lambda p, l, b: self.model.loss(p, b, lora=l, lora_scale=self.scale)
+        )(params, lora, batch)
+        n = saved_residual_bytes(jaxpr.jaxpr)
+        self.recorder.gauge("step.saved_residual_bytes").set(n)
+        self.recorder.round_set(rnd, saved_residual_bytes=n)
+        self._residuals_gauged = True
 
     def _evaluate(self, params, lora, rnd: int) -> tuple[float, float]:
         return evaluate_on_batches(self.eval_fn, params, lora,

@@ -57,7 +57,7 @@ from repro.core.federated import (RoundRecord, evaluate_on_batches,
                                   make_eval_fn, resolve_divergences)
 from repro.core.lora import init_lora
 from repro.optim import adamw_update, clip_by_global_norm, init_adamw, lr_at
-from repro.sharding import client_stack_spec
+from repro.sharding import client_axis_size, client_stack_spec
 from repro.util.logging import get_logger
 from repro.util.tree import flatten_with_paths, unflatten_from_paths
 
@@ -74,7 +74,7 @@ MESH_METHODS = ("fedex", "fedex_svd")
 
 def make_mesh_round_fn(model, lora_scale: float,
                        train_cfg: TrainConfig,
-                       masked: bool = False) -> Callable:
+                       masked: bool = False, lanes: int = 1) -> Callable:
     """One round of local training for ALL lanes in a single jitted program.
 
     ``round_fn(params, lora_stack, batches, lrs)`` scans a lane's
@@ -96,6 +96,9 @@ def make_mesh_round_fn(model, lora_scale: float,
     loss, so ``losses[:, -1]`` remains "the lane's final training loss".
     The default path compiles WITHOUT the masking selects and is
     bitwise-unchanged.
+
+    ``lanes`` is how many lanes share one device: the forward's remat
+    reckoning sees one lane's shapes and multiplies by it.
     """
 
     def one_lane(params, lora, batches, lrs, budget):
@@ -107,7 +110,7 @@ def make_mesh_round_fn(model, lora_scale: float,
 
             def loss_fn(l):
                 return model.loss(params, batch, lora=l,
-                                  lora_scale=lora_scale)
+                                  lora_scale=lora_scale, lanes=lanes)
 
             (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(lora)
             grads, _ = clip_by_global_norm(grads, train_cfg.grad_clip)
@@ -338,9 +341,14 @@ class MeshFederatedTrainer:
         # the default budget-free path keeps its bitwise-unchanged program
         self._budgets = (jnp.asarray(fc.client_local_steps, jnp.int32)
                          if fc.client_local_steps else None)
-        self.round_fn = make_mesh_round_fn(self.model, self.scale,
-                                           self.train_cfg,
-                                           masked=self._budgets is not None)
+        # lanes per device: the client axis splits them, or (when it does
+        # not divide C_max) every device holds them all
+        n_axis = client_axis_size(self.mesh)
+        lanes = (fc.num_clients // n_axis if fc.num_clients % n_axis == 0
+                 else fc.num_clients)
+        self.round_fn = make_mesh_round_fn(
+            self.model, self.scale, self.train_cfg,
+            masked=self._budgets is not None, lanes=lanes)
         self.eval_fn = make_eval_fn(self.model, self.scale)
         self.history: List[RoundRecord] = []
         self._total_steps = fc.rounds * fc.local_steps

@@ -14,6 +14,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.models.common import (
     Params,
@@ -416,14 +417,16 @@ def attention_block(
             new_cache = {"k": k[:, :length].astype(cache["k"].dtype),
                          "v": v[:, :length].astype(cache["v"].dtype),
                          "pos": jnp.arange(length, dtype=jnp.int32)}
+        # named for the train scan's remat policy (transformer._scan_layers)
         out = flash_attention(
-            q, k, v,
+            checkpoint_name(q, "q"), checkpoint_name(k, "k"),
+            checkpoint_name(v, "v"),
             causal and not cross,
             window,
             0,
             block_size,
         )
 
-    out = out.reshape(b, sq, h * hd).astype(x.dtype)
+    out = checkpoint_name(out.reshape(b, sq, h * hd).astype(x.dtype), "attn_out")
     out = dense(out, params["o_proj"], maybe_lora(lora, "o_proj"), lora_scale)
     return out.astype(x.dtype), new_cache

@@ -6,6 +6,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.models.common import Params, activation, dense, make_dense_params, maybe_lora
 
@@ -34,9 +35,12 @@ def mlp_block(cfg, params: Params, x: jnp.ndarray, *, lora: Optional[Params] = N
         # weight-stationary serving layout (ff sharded over BOTH axes) holds
         # without per-step weight gathers (§Perf it. 7, generalised from MoE).
         x = _act.constrain(x, tuple(None for _ in range(x.ndim)))
-    up = dense(x, params["up_proj"], maybe_lora(lora, "up_proj"), lora_scale)
+    up = checkpoint_name(
+        dense(x, params["up_proj"], maybe_lora(lora, "up_proj"), lora_scale), "up")
     if "gate_proj" in params:
-        gate = dense(x, params["gate_proj"], maybe_lora(lora, "gate_proj"), lora_scale)
+        gate = checkpoint_name(
+            dense(x, params["gate_proj"], maybe_lora(lora, "gate_proj"), lora_scale),
+            "gate")
         h = activation(cfg.act, gate) * up
     else:
         h = activation(cfg.act, up)

@@ -3,8 +3,10 @@
 ``Model`` is a thin namespace of pure functions closed over the config:
 
 * ``init(rng) → params``
-* ``apply(params, batch, lora=…) → (logits, aux)`` — training forward
-* ``loss(params, batch, lora=…) → (scalar, metrics)``
+* ``apply(params, batch, lora=…, lanes=1) → (logits, aux)`` — training
+  forward; ``lanes`` counts copies vmapped side by side on one device (the
+  train scan's memory reckoning, transformer.TrainMemory)
+* ``loss(params, batch, lora=…, lanes=1) → (scalar, metrics)``
 * ``init_cache(batch_size, cache_len) → cache``
 * ``prefill(params, batch, cache, lora=…) → (logits, cache)``
 * ``decode_step(params, tokens, cache, position, lora=…) → (logits, cache)``
@@ -46,7 +48,7 @@ def build_model(cfg, *, moe_impl: str = "ragged", block_size: int = 1024) -> Mod
         def init(rng):
             return encdec.make_params(rng, cfg)
 
-        def apply(params, batch, lora=None, lora_scale=0.0):
+        def apply(params, batch, lora=None, lora_scale=0.0, lanes=1):
             enc_out = encdec.encode(cfg, params, batch["frames"], lora=lora,
                                     lora_scale=lora_scale, remat=True,
                                     block_size=block_size)
@@ -78,11 +80,11 @@ def build_model(cfg, *, moe_impl: str = "ragged", block_size: int = 1024) -> Mod
         def init(rng):
             return transformer.make_params(rng, cfg)
 
-        def apply(params, batch, lora=None, lora_scale=0.0):
+        def apply(params, batch, lora=None, lora_scale=0.0, lanes=1):
             logits, aux, _ = transformer.forward(
                 cfg, params, batch["tokens"], lora=lora, lora_scale=lora_scale,
                 mode="train", extra_embeds=batch.get("vision_embeds"),
-                moe_impl=moe_impl, block_size=block_size)
+                moe_impl=moe_impl, block_size=block_size, lanes=lanes)
             return logits, aux
 
         def init_cache(batch_size, cache_len, dtype=jnp.bfloat16):
@@ -103,8 +105,9 @@ def build_model(cfg, *, moe_impl: str = "ragged", block_size: int = 1024) -> Mod
                 moe_impl=moe_impl, block_size=block_size)
             return logits, cache
 
-    def loss(params, batch, lora=None, lora_scale=0.0):
-        logits, aux = apply(params, batch, lora=lora, lora_scale=lora_scale)
+    def loss(params, batch, lora=None, lora_scale=0.0, lanes=1):
+        logits, aux = apply(params, batch, lora=lora, lora_scale=lora_scale,
+                            lanes=lanes)
         targets = batch["targets"]
         mask = batch.get("loss_mask")
         if fam == "vlm" and "vision_embeds" in batch:

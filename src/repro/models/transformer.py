@@ -10,8 +10,14 @@ Heterogeneous architectures scan over their *period*:
 * xlstm:  period = (slstm_every − 1) mLSTM blocks + 1 sLSTM block
 * deepseek-v2: ``first_k_dense`` dense-FFN MLA layers, then MLA+MoE layers
 
-Modes: ``train`` (remat'd, no cache), ``prefill`` (fills caches), ``decode``
-(single token against caches). MoE aux losses accumulate through the scan.
+Modes: ``train`` (no cache), ``prefill`` (fills caches), ``decode`` (single
+token against caches). MoE aux losses accumulate through the scan. In train
+mode every scanned layer body runs under ``jax.checkpoint``: when the device
+reports a memory limit and the named matmul outputs the backward reads
+(:data:`SAVED_NAMES`) fit beside the parameters and the f32 logits, the scan
+keeps them and the backward recomputes only norms and rank-r products;
+otherwise (or on a device with no limit: the CPU, host-side compiles) nothing
+is kept and the backward re-runs each layer's forward.
 """
 
 from __future__ import annotations
@@ -21,6 +27,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.extend import core as jax_core
 
 from repro.models import attention as attn_mod
 from repro.models import mla as mla_mod
@@ -99,7 +107,7 @@ def _attn_mlp_layer(cfg, p, x, *, lora, lora_scale, positions, window, cache,
             cfg, p["attn"], h_in, lora=(lora or {}).get("attn"),
             lora_scale=lora_scale, positions=positions, window=window,
             cache=cache, decode_position=decode_position, block_size=block_size)
-    x = x + h
+    x = checkpoint_name(x + h, "resid_mid")
     m_in = apply_norm(cfg.norm, p["mlp_norm"], x)
     aux = jnp.zeros((), jnp.float32)
     if "router" in p["mlp"]:
@@ -125,9 +133,108 @@ def _mamba_layer(cfg, p, x, *, lora, lora_scale, cache, decode):
 # scan runner
 # ==========================================================================
 
-def _scan_layers(body, x, xs, *, remat: bool):
-    """scan ``body(x, xs_slice) → (x, ys_slice)`` over the leading layer axis."""
-    fn = jax.checkpoint(body) if remat else body
+# The layer's matmul outputs its backward reads, as checkpoint_name tags them:
+# q/k/v as flash attention receives them and its output before o_proj
+# (attention.attention_block), the residual after attention
+# (_attn_mlp_layer), gate and up (mlp.mlp_block). down_proj's output is read
+# by nothing in the backward, so it is not kept.
+SAVED_NAMES = ("q", "k", "v", "attn_out", "resid_mid", "gate", "up")
+# Share of the device memory left beside the fixed bytes that the kept
+# residuals may take; the rest is for the backward's temporaries and the
+# state held between programs (optimizer, ring buffers).
+HEADROOM_SHARE = 0.75
+
+
+def residual_bytes_kept(saved: int, fixed: int, limit: Optional[int]) -> int:
+    """Bytes of named residuals a train scan keeps: ``saved`` when it fits in
+    :data:`HEADROOM_SHARE` of what ``limit`` leaves beside ``fixed`` (bytes
+    already spoken for), else 0 — full remat. No ``limit`` → 0."""
+    if not limit or saved <= 0:
+        return 0
+    return saved if saved <= HEADROOM_SHARE * (limit - fixed) else 0
+
+
+@functools.lru_cache(maxsize=None)
+def device_bytes_limit() -> Optional[int]:
+    """The default device's ``memory_stats()["bytes_limit"]``, or None where
+    the device reports none (the CPU). A constant of the process, so a call
+    compiles the same program every time."""
+    stats = jax.devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
+class TrainMemory:
+    """What a train forward's remat'd scans may keep: the device ``limit``,
+    the bytes already spoken for (``fixed``: parameters, f32 logits and their
+    gradient, residuals earlier stacks kept) and the ``lanes`` of this forward
+    that run side by side on one device (vmapped clients)."""
+
+    def __init__(self, limit: Optional[int], fixed: int, lanes: int = 1):
+        self.limit, self.fixed, self.lanes = limit, fixed, lanes
+
+    def checkpoint(self, body, x, xs):
+        """``jax.checkpoint(body)`` saving :data:`SAVED_NAMES` when the scan's
+        named residuals (one layer's × layers × lanes) fit; else exactly
+        ``jax.checkpoint(body)``."""
+        if not self.limit:
+            return jax.checkpoint(body)
+        one = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), x)
+        layer = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), xs)
+        n = jax.tree_util.tree_leaves(xs)[0].shape[0]
+        saved = named_bytes(jax.make_jaxpr(body)(one, layer).jaxpr) * n * self.lanes
+        kept = residual_bytes_kept(saved, self.fixed, self.limit)
+        if not kept:
+            return jax.checkpoint(body)
+        self.fixed += kept
+        return jax.checkpoint(
+            body, policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in v if isinstance(v, (tuple, list)) else (v,):
+            if isinstance(j, jax_core.ClosedJaxpr):
+                yield j.jaxpr
+            elif isinstance(j, jax_core.Jaxpr):
+                yield j
+
+
+def named_bytes(jaxpr) -> int:
+    """Bytes of the values ``jaxpr`` tags with :data:`SAVED_NAMES` (inner
+    scans count once per iteration)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name" and eqn.params["name"] in SAVED_NAMES:
+            total += sum(v.aval.size * v.aval.dtype.itemsize for v in eqn.outvars)
+        reps = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        total += reps * sum(named_bytes(j) for j in _sub_jaxprs(eqn))
+    return total
+
+
+def saved_residual_bytes(jaxpr) -> int:
+    """Bytes the remat'd scans of a traced train forward keep for the
+    backward: the named values under every ``jax.checkpoint`` with a policy,
+    times the scan lengths around it. 0 under full remat."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        reps = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        if eqn.params.get("policy") is not None:
+            total += reps * sum(named_bytes(j) for j in _sub_jaxprs(eqn))
+        else:
+            total += reps * sum(saved_residual_bytes(j) for j in _sub_jaxprs(eqn))
+    return total
+
+
+def _tree_bytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(tree))
+
+
+def _scan_layers(body, x, xs, *, remat):
+    """scan ``body(x, xs_slice) → (x, ys_slice)`` over the leading layer axis.
+
+    ``remat`` is False (no checkpoint) or the forward's :class:`TrainMemory`,
+    which picks what the checkpointed body keeps."""
+    fn = remat.checkpoint(body, x, xs) if remat else body
 
     def wrapped(carry, inp):
         return fn(carry, inp)
@@ -289,11 +396,11 @@ def forward(
     extra_embeds: Optional[jnp.ndarray] = None,  # vlm: (B, Vt, d) patch embeds
     moe_impl: str = "ragged",
     block_size: int = 1024,
+    lanes: int = 1,  # train: copies of this forward vmapped on one device
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[Params]]:
     """Returns (logits (B,S,V) f32, aux_loss scalar, new_cache)."""
     b, s = tokens.shape
     decode = mode == "decode"
-    remat = mode == "train"
     x = embed(params["embed"], tokens)
 
     offset = 0
@@ -302,6 +409,15 @@ def forward(
         vis = dense_fn(extra_embeds.astype(x.dtype), params["vision_proj"])
         x = jnp.concatenate([vis, x], axis=1)
         s = x.shape[1]
+
+    remat = False
+    if mode == "train":
+        # spoken for before any residual: the parameters, and per lane the
+        # adapters and the f32 logits with their gradient
+        logits_bytes = 2 * b * s * cfg.vocab_size * 4
+        remat = TrainMemory(device_bytes_limit(),
+                            _tree_bytes(params) + lanes * (_tree_bytes(lora) + logits_bytes),
+                            lanes)
 
     if decode:
         positions = None
