@@ -7,7 +7,13 @@ that reads it (``bench/traffic/<generator>.py``), and the configuration
 file its plain
 reference (``bench/reference/<reference>.py``). Per-layer metrics are
 ``bench/metrics/<metric>.py``. Adding any of them is adding files plus
-``BENCHMARK.json`` entries; nothing here changes.
+``BENCHMARK.json`` entries; nothing here changes. A configuration reaches
+the program through ``bench/traffic/program.py``'s table of published keys
+and its ``"program"`` object, and is counted by ``harness/flops.py`` (GQA
+or latent attention, dense or routed-expert layers), so a model the program
+builds needs no edit either; one of another architecture brings its
+reference as a new file. A cell's CPU twin is
+``bench/tests/data/tiny/<cell>.json``.
 """
 
 from __future__ import annotations

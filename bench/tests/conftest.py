@@ -13,29 +13,8 @@ import time
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
+sys.path.insert(0, os.path.join(BENCH, "traffic"))  # generators' siblings
 sys.path.insert(0, os.path.join(ROOT, "src"))
-
-TINY = {"registry": "qwen2.5-3b", "reference": "decoder", "hidden_size": 64,
-        "intermediate_size": 128, "num_hidden_layers": 2,
-        "num_attention_heads": 4, "num_key_value_heads": 2,
-        "vocab_size": 128, "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
-        "tie_word_embeddings": True, "qkv_bias": True,
-        "base_dtype": "bfloat16", "lora_rank": 4, "lora_alpha": 8.0,
-        "lora_targets": ["q_proj", "k_proj", "v_proj", "o_proj",
-                         "gate_proj", "up_proj", "down_proj"]}
-
-CELLS = {
-    "qwen2.5-3b.train.silo3": (TINY, dict(batch=4, seq_len=16, eval_batch=4,
-                                          min_rows=16, extra_rows=16,
-                                          close_sample_every=1)),
-    "granite-8b.l8.serve.c16": (dict(TINY, registry="granite-8b",
-                                     tie_word_embeddings=False,
-                                     qkv_bias=False), {}),
-    "granite-8b.l8.serve.c4": (dict(TINY, registry="granite-8b",
-                                    tie_word_embeddings=False,
-                                    qkv_bias=False), {}),
-}
-
 
 # The served cells are parked (PERF.md, Open questions): their generator,
 # mixes, configuration and limits stay under bench/ for a later PR to list
@@ -52,18 +31,25 @@ PARKED = {
 }
 
 
+def bench_json(path):
+    """A JSON file of the benchmark, by its path under ``bench/``."""
+    with open(os.path.join(BENCH, path)) as f:
+        return json.load(f)
+
+
 def tiny_cell(name):
     """The named cell of ``BENCHMARK.json`` (or a parked one) at a size a
-    CPU runs quickly."""
+    CPU runs quickly: its twin ``data/tiny/<cell>.json`` holds the
+    configuration at tiny widths and the traffic keys it overrides."""
     from harness import spec
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     for k, entries in PARKED.items():
         bench[k] = bench[k] + entries
     cell = spec.load_cell(name, bench)
-    cfg, traffic = CELLS[name]
-    cell.config = dict(cfg)
-    cell.traffic = dict(cell.traffic, **traffic)
+    twin = bench_json(os.path.join("tests", "data", "tiny", name + ".json"))
+    cell.config = twin["config"]
+    cell.traffic = dict(cell.traffic, **twin["traffic"])
     return cell
 
 

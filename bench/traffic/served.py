@@ -34,7 +34,7 @@ from harness import flops, weights
 from harness.weights import jnp_dtype
 from harness.device import device_stamp, log
 from harness.runner import Check, Outcome, Run, reduce_trace
-from program import seeded_state, w0_keys, w0_leaf
+from program import seeded_state, w0_keys, w0_leaf, w0_path
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 # host spans that can name an idle gap, most specific first
@@ -246,7 +246,7 @@ def reference_outputs(cfg, tr, seed, n_ex, rounds, lshape, mode="f32",
                 out["lora"][key + "/a"] = np.asarray(abar)
                 out["lora"][key + "/b"] = np.asarray(bbar)
             del a, b
-        w0 = weights.make_leaf(wshape, seed, 1, key + "/kernel")
+        w0 = weights.make_leaf(wshape, seed, 1, w0_path(wshape, key))
         done = 0 if fault == "unchanged" else rounds
         out["w0"][key] = np.asarray(ref.replay(w0, res[0], res[1], scale,
                                                done))

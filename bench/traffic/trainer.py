@@ -34,7 +34,7 @@ from harness import flops, weights
 from harness.weights import jnp_dtype
 from harness.device import device_stamp, log
 from harness.runner import Check, Outcome, Run, reduce_trace
-from program import seeded_state, w0_keys, w0_leaf
+from program import seeded_state, w0_keys, w0_leaf, w0_path
 
 # host spans that can name an idle gap in a traced round, most specific
 # first (benchmark spans around the program's calls, then repro.obs spans)
@@ -115,7 +115,8 @@ def _annotated(fn, name):
 
 
 def build(r: Run):
-    """The trainer with the benchmark's weights, and the step capture."""
+    """The trainer with the benchmark's weights, and the captures of its
+    steps and of its closes."""
     from repro.configs import FedConfig, TrainConfig
     from repro.core import FederatedTrainer
     cfg, tr = r.cell.config, r.cell.traffic
@@ -145,7 +146,11 @@ def build(r: Run):
     del params
     cap = StepCapture(trainer.local_step)
     trainer.local_step = r.hook("local_step", cap)
-    return r.hook("trainer", trainer), cap
+    trainer = r.hook("trainer", trainer)
+    closer = CloseCapture(trainer.engine.close, sample_rows(
+        wshape, lshape, r.seed, tr["close_sample_every"]))
+    trainer.engine.close = closer
+    return trainer, cap, closer
 
 
 def close_inputs(trainer) -> Dict[str, Any]:
@@ -165,7 +170,7 @@ def sample_rows(wshape, lshape, seed: int, every: int) -> Dict[str, Any]:
     rng = np.random.default_rng(weights.seed_words(seed, 5))
     rows = {}
     for key in w0_keys(lshape):
-        m = wshape[key + "/kernel"].shape[-2]
+        m = wshape[w0_path(wshape, key)].shape[-2]
         rows[key] = np.sort(rng.choice(m, max(1, m // every), replace=False))
     return rows
 
@@ -184,6 +189,7 @@ class CloseCapture:
         import jax
         import jax.numpy as jnp
         self.close = close
+        self.sampled = rows
         self.take = jax.jit(_take)
         self.rows = {k: jnp.asarray(v, jnp.int32) for k, v in rows.items()}
         self.before = None
@@ -198,14 +204,22 @@ class CloseCapture:
         return self.close(params, client_ids, weights, **kw)
 
 
+def last_close(trainer, closer: CloseCapture) -> Dict[str, Any]:
+    """The last close as the comparison reads it, on the host: its inputs,
+    the sampled W0 rows before and after it, and the global adapter it
+    left."""
+    def host(tree):
+        return {k: np.asarray(v) for k, v in tree.items()}
+    return dict(close_inputs(trainer), rows=closer.sampled,
+                before=host(closer.before),
+                after=host(closer.gather(trainer.params)),
+                lora=host(weights.flatten(trainer.global_lora)))
+
+
 def run(r: Run) -> Outcome:
     import jax
     cfg, tr = r.cell.config, r.cell.traffic
-    trainer, cap = build(r)
-    _, wshape, lshape, _ = seeded_state(cfg)
-    rows = sample_rows(wshape, lshape, r.seed, tr["close_sample_every"])
-    closer = CloseCapture(trainer.engine.close, rows)
-    trainer.engine.close = closer
+    trainer, cap, closer = build(r)
     trainer.run(until=1)                       # round 0: compiles, captured
     trainer.local_step = r.hook("local_step", cap.step)
     t0 = time.time()
@@ -237,12 +251,7 @@ def run(r: Run) -> Outcome:
 
     # to the host: the last close's inputs and what it left, the captured
     # steps; then the program's state is freed before the reference runs
-    last = dict(close_inputs(trainer), rows=rows,
-                before={k: np.asarray(v) for k, v in closer.before.items()},
-                after={k: np.asarray(v) for k, v in
-                       closer.gather(trainer.params).items()},
-                lora={k: np.asarray(v) for k, v in
-                      weights.flatten(trainer.global_lora).items()})
+    last = last_close(trainer, closer)
     capt = [{k: jax.tree.map(np.asarray, v) for k, v in c.items()}
             for c in cap.calls]
     del trainer, cap, closer
