@@ -15,6 +15,22 @@ from typing import Dict, Optional, Tuple
 
 
 @dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling as the published configs state it (DeepSeek-V2's
+    ``rope_scaling``): the frequency interpolation between ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position_embeddings``, and
+    the ``mscale`` / ``mscale_all_dim`` factors on cos/sin and the softmax."""
+
+    type: str = "yarn"
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | ssm | hybrid | encdec | vlm
@@ -36,6 +52,9 @@ class ModelConfig:
     local_window: int = 0  # window used by "local" layers
     max_position_embeddings: int = 131_072
     learned_pos_embeddings: bool = False  # whisper-style
+    # None → plain rope; a RopeScaling (or its dict, as a config file gives
+    # it) → YaRN frequencies and softmax mscale (MLA only)
+    rope_scaling: Optional[RopeScaling] = None
 
     # --- MLA (DeepSeek-V2) ---------------------------------------------------
     mla: bool = False
@@ -54,6 +73,20 @@ class ModelConfig:
     dense_d_ff: int = 0  # d_ff for those leading dense layers
     router_aux_loss_coef: float = 0.01
     capacity_factor: float = 1.25
+    # routing: "greedy" (top-k over all experts) or "group_limited_greedy"
+    # (top ``topk_group`` of ``n_group`` groups by their best expert, then
+    # top-k inside them); the top-k weights are renormalised when
+    # ``norm_topk_prob``, else scaled by ``routed_scaling_factor``
+    topk_method: str = "greedy"
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # expert parallelism: this layer holds ``local_experts`` of the router's
+    # ``num_experts`` (0 → all), starting at ``expert_offset``; it routes over
+    # all of them and computes only its own experts' part
+    local_experts: int = 0
+    expert_offset: int = 0
 
     # --- SSM / hybrid --------------------------------------------------------
     ssm_state: int = 0
@@ -79,6 +112,31 @@ class ModelConfig:
     dtype: str = "bfloat16"
 
     # ------------------------------------------------------------------------
+    def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               RopeScaling(**self.rope_scaling))
+        if self.rope_scaling is not None and self.rope_scaling.type != "yarn":
+            raise ValueError(f"unknown rope_scaling {self.rope_scaling.type!r}")
+        if self.topk_method not in ("greedy", "group_limited_greedy"):
+            raise ValueError(f"unknown topk_method {self.topk_method!r}")
+        if self.num_experts:
+            if self.num_experts % self.n_group or not (
+                    1 <= self.topk_group <= self.n_group):
+                raise ValueError(
+                    f"{self.num_experts} experts cannot be routed in "
+                    f"{self.n_group} groups, top {self.topk_group}")
+            if not 0 <= self.expert_offset < self.num_experts or \
+                    self.expert_offset + self.local_experts > self.num_experts:
+                raise ValueError(
+                    f"experts held [{self.expert_offset}, +{self.local_experts})"
+                    f" lie outside the router's {self.num_experts}")
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this layer holds."""
+        return self.local_experts or self.num_experts
+
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
@@ -123,6 +181,12 @@ class ModelConfig:
                 first_k_dense=min(self.first_k_dense, 1),
                 dense_d_ff=min(self.dense_d_ff, 256) if self.dense_d_ff else 0,
             )
+            e = kw["num_experts"]
+            groups = min(self.n_group, e)
+            while e % groups:
+                groups -= 1
+            kw.update(n_group=groups, topk_group=min(self.topk_group, groups),
+                      local_experts=min(self.local_experts, e), expert_offset=0)
         if self.mla:
             kw.update(kv_lora_rank=32, q_lora_rank=64, qk_rope_head_dim=16,
                       qk_nope_head_dim=32, v_head_dim=32)

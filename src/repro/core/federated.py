@@ -53,6 +53,9 @@ def _freeze_a(grads):
 
 def make_local_step(model, lora_scale: float, train_cfg: TrainConfig,
                     freeze_a: bool = False) -> Callable:
+    """The jitted local step: ``(lora, opt_state, loss, stats)``. ``stats``
+    holds ``grad_norm`` and, for a model with routed-expert layers, the
+    loss's ``metrics["moe"]`` routing counts."""
     @jax.jit
     def step(params, lora, opt_state, batch, lr):
         def loss_fn(l):
@@ -66,7 +69,8 @@ def make_local_step(model, lora_scale: float, train_cfg: TrainConfig,
             grads, opt_state, lora, learning_rate=lr,
             beta1=train_cfg.beta1, beta2=train_cfg.beta2, eps=train_cfg.eps,
             weight_decay=train_cfg.weight_decay)
-        return lora, opt_state, loss, gnorm
+        return lora, opt_state, loss, dict(metrics.get("moe", {}),
+                                           grad_norm=gnorm)
 
     return step
 
@@ -456,9 +460,15 @@ class FederatedTrainer:
                 if not self._residuals_gauged:
                     self._gauge_saved_residuals(params, lora, batch, rnd)
                 with rec.span("step.dispatch", cat="trainer"):
-                    lora, opt_state, loss, gnorm = self.local_step(
+                    lora, opt_state, loss, stats = self.local_step(
                         params, lora, opt_state, batch, lr)
                 with rec.span("step.sync", cat="trainer"):
+                    if rec.enabled and "held_rows" in stats:
+                        # the routing counts come with the loss, in one fetch
+                        loss, stats = jax.device_get((loss, stats))
+                        rec.counter("moe.held_rows").inc(int(stats["held_rows"]))
+                        rec.hist("moe.max_expert_rows").observe(
+                            int(stats["max_expert_rows"]))
                     losses.append(float(loss))
             if rec.enabled:
                 rec.host_sync("step", 1, rnd)
@@ -478,6 +488,15 @@ class FederatedTrainer:
         n = saved_residual_bytes(jaxpr.jaxpr)
         self.recorder.gauge("step.saved_residual_bytes").set(n)
         self.recorder.round_set(rnd, saved_residual_bytes=n)
+        cfg = self.model.cfg
+        if getattr(cfg, "is_moe", False):
+            # the expert layer's shape: experts held here, the router's
+            # width, and the rows each grouped GEMM is given (tokens × k)
+            tokens = batch["tokens"].size
+            self.recorder.gauge("moe.experts_held").set(cfg.held_experts)
+            self.recorder.gauge("moe.router_width").set(cfg.num_experts)
+            self.recorder.gauge("moe.sorted_rows").set(
+                tokens * cfg.num_experts_per_tok)
         self._residuals_gauged = True
 
     def _evaluate(self, params, lora, rnd: int) -> tuple[float, float]:

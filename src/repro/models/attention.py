@@ -139,8 +139,9 @@ def blockwise_attention(
 # out + lse, and the attention boundary cotangent becomes bf16.
 # --------------------------------------------------------------------------
 
-def _flash_reshape(q, k, v):
-    """Shared GQA/model-axis prep: returns (qg*scale, k, v, kvh, group)."""
+def _flash_reshape(q, k, v, scale=None):
+    """Shared GQA/model-axis prep: returns (qg*scale, k, v, kvh, group);
+    ``scale`` None → ``dk**-0.5``."""
     from repro.sharding import act as _act
 
     b, sq, h, dk = q.shape
@@ -156,7 +157,7 @@ def _flash_reshape(q, k, v):
         k = _act.constrain(k, ("dp", None, "model", None))
         v = _act.constrain(v, ("dp", None, "model", None))
     group = h // kvh
-    scale = dk ** -0.5
+    scale = dk ** -0.5 if scale is None else scale
     qg = q.reshape(b, sq, kvh, group, dk).astype(jnp.float32) * scale
     return qg, k, v, kvh, group
 
@@ -171,10 +172,10 @@ def _block_mask(sq, block_size, blk_idx, sk, q_offset, causal, window):
     return mask & (k_pos < sk)[None, :]
 
 
-def _flash_fwd_impl(q, k, v, causal, window, q_offset, block_size):
+def _flash_fwd_impl(q, k, v, causal, window, q_offset, block_size, scale=None):
     b, sq, h, dk = q.shape
     sk = k.shape[1]
-    qg, k, v, kvh, group = _flash_reshape(q, k, v)
+    qg, k, v, kvh, group = _flash_reshape(q, k, v, scale)
     dv_dim = v.shape[-1]
     bs = min(block_size, sk)
     pad = (-sk) % bs
@@ -212,11 +213,12 @@ def _flash_fwd_impl(q, k, v, causal, window, q_offset, block_size):
     return out.astype(v.dtype), lse
 
 
-def _flash_bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset, block_size):
+def _flash_bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset, block_size,
+                    scale=None):
     b, sq, h, dk = q.shape
     sk = k.shape[1]
     kvh_orig = k.shape[2]
-    qg, k, v, kvh, group = _flash_reshape(q, k, v)
+    qg, k, v, kvh, group = _flash_reshape(q, k, v, scale)
     dv_dim = v.shape[-1]
     bs = min(block_size, sk)
     pad = (-sk) % bs
@@ -252,7 +254,7 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset, block_siz
 
     dq0 = jnp.zeros((b, sq, kvh, group, dk), jnp.float32)
     dq, (dks, dvs) = jax.lax.scan(body, dq0, (jnp.arange(nblocks), kb, vb))
-    scale = dk ** -0.5
+    scale = dk ** -0.5 if scale is None else scale
     dq = (dq * scale).reshape(b, sq, h, dk)
     dk_full = dks.transpose(1, 0, 2, 3, 4).reshape(b, nblocks * bs, kvh, dk)[:, :sk]
     dv_full = dvs.transpose(1, 0, 2, 3, 4).reshape(b, nblocks * bs, kvh, dv_dim)[:, :sk]
@@ -263,22 +265,25 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset, block_siz
     return (dq.astype(q.dtype), dk_full.astype(q.dtype), dv_full.astype(q.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, window=0, q_offset=0,
-                    block_size=1024):
-    out, _ = _flash_fwd_impl(q, k, v, causal, window, q_offset, block_size)
+                    block_size=1024, scale=None):
+    """Softmax(q·kᵀ · scale) v; ``scale`` None → ``q.shape[-1]**-0.5``."""
+    out, _ = _flash_fwd_impl(q, k, v, causal, window, q_offset, block_size,
+                             scale)
     return out
 
 
-def _flash_fwd(q, k, v, causal, window, q_offset, block_size):
-    out, lse = _flash_fwd_impl(q, k, v, causal, window, q_offset, block_size)
+def _flash_fwd(q, k, v, causal, window, q_offset, block_size, scale):
+    out, lse = _flash_fwd_impl(q, k, v, causal, window, q_offset, block_size,
+                               scale)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, window, q_offset, block_size, res, dout):
+def _flash_bwd(causal, window, q_offset, block_size, scale, res, dout):
     q, k, v, out, lse = res
     return _flash_bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset,
-                           block_size)
+                           block_size, scale)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

@@ -10,14 +10,20 @@ Decode uses *weight absorption* (the TPU-friendly form): queries are mapped
 into the latent space through ``w_uk`` so scores are taken directly against the
 compressed cache, and attention output is re-expanded through ``w_uv`` — per
 step cost is O(S · kv_lora_rank) per head instead of decompressing the cache.
+
+The rope dims follow the published model: rotated as interleaved pairs, and,
+under ``rope_scaling``, at YaRN frequencies with the YaRN ``mscale`` on
+cos/sin and, squared, on the softmax scale.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.attention import flash_attention
 from repro.models.common import (
@@ -31,6 +37,81 @@ from repro.models.common import (
 )
 
 NEG_INF = -1e30
+# the most one f32 score block of the training attention scan may take: the
+# key block shrinks (to 128 at least) until (B, H, Sq, block) fits
+SCORE_BLOCK_BYTES = 1 << 29
+
+
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, base: float,
+                         max_pos: int) -> float:
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(base)))
+
+
+def yarn_find_correction_range(beta_fast: float, beta_slow: float, dim: int,
+                               base: float, max_pos: int) -> Tuple[int, int]:
+    low = math.floor(_yarn_correction_dim(beta_fast, dim, base, max_pos))
+    high = math.ceil(_yarn_correction_dim(beta_slow, dim, base, max_pos))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_frequencies(dim: int, base: float, rs) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies (dim//2,) float32, cos/sin mscale) of YaRN: the
+    interpolated ``1/(factor·base^(2i/dim))`` below the correction range, the
+    original ``1/base^(2i/dim)`` above it, a linear ramp between."""
+    pos = np.arange(0, dim, 2, dtype=np.float32) / dim
+    extra = (1.0 / np.float32(base) ** pos).astype(np.float32)
+    inter = (1.0 / (np.float32(rs.factor) * np.float32(base) ** pos)
+             ).astype(np.float32)
+    low, high = yarn_find_correction_range(
+        rs.beta_fast, rs.beta_slow, dim, base,
+        rs.original_max_position_embeddings)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = (1.0 - ramp).astype(np.float32)
+    inv = (inter * (1 - keep) + extra * keep).astype(np.float32)
+    m = (yarn_get_mscale(rs.factor, rs.mscale)
+         / yarn_get_mscale(rs.factor, rs.mscale_all_dim))
+    return inv, m
+
+
+def softmax_scale(cfg) -> float:
+    """``(qk_nope + qk_rope)^-0.5``, times YaRN's ``mscale(factor,
+    mscale_all_dim)²`` under YaRN."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_get_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
+def mla_rope(cfg, x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
+    """Rotate the rope dims ``x (..., seq, heads, d)``: de-interleave the
+    pairs (the published layout), then rotate halves at plain or YaRN
+    frequencies."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    if cfg.rope_scaling is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    inv, m = yarn_frequencies(x.shape[-1], cfg.rope_theta, cfg.rope_scaling)
+    angles = positions[..., None].astype(jnp.float32) * inv
+    cos = (jnp.cos(angles) * m)[..., None, :]
+    sin = (jnp.sin(angles) * m)[..., None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _key_block(b: int, h: int, sq: int, sk: int, block_size: int) -> int:
+    bs = min(block_size, sk)
+    while bs > 128 and b * h * sq * bs * 4 > SCORE_BLOCK_BYTES:
+        bs //= 2
+    return bs
 
 
 def make_mla_params(rng, cfg) -> Params:
@@ -103,10 +184,11 @@ def mla_block(
 
     q_nope, q_rope = _project_q(cfg, params, x, lora, lora_scale)
     q_pos = decode_position[None] if is_decode else positions
-    q_rope = apply_rope(q_rope, q_pos, cfg.rope_theta)
+    q_rope = mla_rope(cfg, q_rope, q_pos)
 
     c_kv, k_rope = _project_kv_latent(cfg, params, x, lora, lora_scale)
-    k_rope = apply_rope(k_rope[..., None, :], q_pos, cfg.rope_theta)[..., 0, :]
+    k_rope = mla_rope(cfg, k_rope[..., None, :], q_pos)[..., 0, :]
+    scale = softmax_scale(cfg)
 
     new_cache = cache
     if is_decode:
@@ -125,7 +207,6 @@ def mla_block(
         w_uv = params["v_up"]["kernel"].reshape(kvr, h, dv)
         # absorb: query → latent space
         q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope, w_uk)  # (B,1,H,kvr)
-        scale = (qk_nope + qk_rope) ** -0.5
         s_nope = jnp.einsum("bqhc,bsc->bhqs", q_lat, c_kv_c, preferred_element_type=jnp.float32)
         s_rope = jnp.einsum("bqhd,bsd->bhqs", q_rope, k_rope_c, preferred_element_type=jnp.float32)
         scores = (s_nope + s_rope) * scale
@@ -143,7 +224,9 @@ def mla_block(
         k_rope_b = jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, qk_rope))
         q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
         k_full = jnp.concatenate([k_nope, k_rope_b], axis=-1)
-        out = flash_attention(q_full, k_full, v, True, 0, 0, block_size)
+        with jax.named_scope("mla.attn"):
+            out = flash_attention(q_full, k_full, v, True, 0, 0,
+                                  _key_block(b, h, s, s, block_size), scale)
         if cache is not None:
             length = cache["c_kv"].shape[1]
             ck, kr = c_kv[:, -length:], k_rope[:, -length:]

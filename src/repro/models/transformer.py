@@ -95,7 +95,9 @@ def _mamba_layer_init(cfg):
 
 def _attn_mlp_layer(cfg, p, x, *, lora, lora_scale, positions, window, cache,
                     decode_position, moe_impl, block_size):
-    """Standard pre-norm transformer layer; returns (x, new_cache, aux)."""
+    """Standard pre-norm transformer layer; returns (x, new_cache, aux,
+    stats): ``stats`` is the expert layer's routing counts (moe.moe_block),
+    None for a dense FFN."""
     h_in = apply_norm(cfg.norm, p["attn_norm"], x)
     if cfg.mla:
         h, new_cache = mla_mod.mla_block(
@@ -110,14 +112,15 @@ def _attn_mlp_layer(cfg, p, x, *, lora, lora_scale, positions, window, cache,
     x = checkpoint_name(x + h, "resid_mid")
     m_in = apply_norm(cfg.norm, p["mlp_norm"], x)
     aux = jnp.zeros((), jnp.float32)
+    stats = None
     if "router" in p["mlp"]:
-        m, aux = moe_mod.moe_block(cfg, p["mlp"], m_in,
-                                   lora=(lora or {}).get("mlp"),
-                                   lora_scale=lora_scale, impl=moe_impl)
+        m, aux, stats = moe_mod.moe_block(cfg, p["mlp"], m_in,
+                                          lora=(lora or {}).get("mlp"),
+                                          lora_scale=lora_scale, impl=moe_impl)
     else:
         m = mlp_block(cfg, p["mlp"], m_in, lora=(lora or {}).get("mlp"),
                       lora_scale=lora_scale)
-    return x + m, new_cache, aux
+    return x + m, new_cache, aux, stats
 
 
 def _mamba_layer(cfg, p, x, *, lora, lora_scale, cache, decode):
@@ -397,8 +400,12 @@ def forward(
     moe_impl: str = "ragged",
     block_size: int = 1024,
     lanes: int = 1,  # train: copies of this forward vmapped on one device
-) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[Params]]:
-    """Returns (logits (B,S,V) f32, aux_loss scalar, new_cache)."""
+) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[Params], Dict[str, jnp.ndarray]]:
+    """Returns (logits (B,S,V) f32, aux_loss scalar, new_cache, stats):
+    ``stats`` is empty but for routed-expert stacks, where it holds this
+    forward's ``held_rows`` (assignments to the experts held here, over all
+    expert layers) and ``max_expert_rows`` (the most any one expert of one
+    layer got)."""
     b, s = tokens.shape
     decode = mode == "decode"
     x = embed(params["embed"], tokens)
@@ -437,6 +444,7 @@ def forward(
 
     aux_total = jnp.zeros((), jnp.float32)
     new_cache = None
+    stats: Dict[str, jnp.ndarray] = {}
 
     lora = lora or {}
     fam = cfg.family
@@ -445,11 +453,11 @@ def forward(
         def body(carry, inp):
             xc, aux = carry
             p, lo, ca = inp
-            xo, nc, a = _attn_mlp_layer(
+            xo, nc, a, st = _attn_mlp_layer(
                 cfg, p, xc, lora=lo, lora_scale=lora_scale, positions=positions,
                 window=window, cache=ca, decode_position=dpos, moe_impl=moe_impl,
                 block_size=block_size)
-            return (xo, aux + a), nc
+            return (xo, aux + a), (nc, st)
         return body
 
     def run_stack(x, layer_params, layer_lora, layer_cache, window):
@@ -458,18 +466,18 @@ def forward(
         if layer_cache is None:
             def body_nc(carry, inp):
                 p, l = inp
-                (xo, aux), _ = attn_body_factory(window)(carry, (p, l, None))
-                return (xo, aux), None
-            (x, aux), _ = _scan_layers(body_nc, (x, jnp.zeros((), jnp.float32)),
-                                       (layer_params, lo), remat=remat)
-            return x, aux, None
-        (x, aux), ncache = _scan_layers(
+                (xo, aux), (_, st) = attn_body_factory(window)(carry, (p, l, None))
+                return (xo, aux), st
+            (x, aux), st = _scan_layers(body_nc, (x, jnp.zeros((), jnp.float32)),
+                                        (layer_params, lo), remat=remat)
+            return x, aux, None, st
+        (x, aux), (ncache, st) = _scan_layers(
             attn_body_factory(window), (x, jnp.zeros((), jnp.float32)),
             (layer_params, lo, layer_cache), remat=False)
-        return x, aux, ncache
+        return x, aux, ncache, st
 
     if fam in ("dense", "vlm") and not cfg.local_global_ratio:
-        x, aux, nc = run_stack(x, params["layers"], lora.get("layers"),
+        x, aux, nc, _ = run_stack(x, params["layers"], lora.get("layers"),
                                None if cache is None else cache["layers"],
                                cfg.sliding_window)
         aux_total += aux
@@ -485,7 +493,7 @@ def forward(
             def local_body(c2, inp2):
                 p2, l2, ca2 = inp2
                 x2, a2 = c2
-                xo, nc2, a = _attn_mlp_layer(
+                xo, nc2, a, _ = _attn_mlp_layer(
                     cfg, p2, x2, lora=l2, lora_scale=lora_scale,
                     positions=positions, window=cfg.local_window, cache=ca2,
                     decode_position=dpos, moe_impl=moe_impl, block_size=block_size)
@@ -502,7 +510,7 @@ def forward(
             else:
                 (xc, aux), nc_local = jax.lax.scan(
                     local_body, (xc, aux), (pp["local"], lo_local, local_ca))
-            xo, nc_glob, a = _attn_mlp_layer(
+            xo, nc_glob, a, _ = _attn_mlp_layer(
                 cfg, pp["global"], xc,
                 lora=None if lo is None else lo["global"],
                 lora_scale=lora_scale, positions=positions, window=0,
@@ -531,18 +539,20 @@ def forward(
     elif fam == "moe":
         new_cache = {} if cache is not None else None
         if cfg.first_k_dense:
-            x, aux, nc = run_stack(x, params["dense_layers"], lora.get("dense_layers"),
-                                   None if cache is None else cache["dense_layers"],
-                                   cfg.sliding_window)
+            x, aux, nc, _ = run_stack(x, params["dense_layers"], lora.get("dense_layers"),
+                                      None if cache is None else cache["dense_layers"],
+                                      cfg.sliding_window)
             aux_total += aux
             if cache is not None:
                 new_cache["dense_layers"] = nc
-        x, aux, nc = run_stack(x, params["layers"], lora.get("layers"),
-                               None if cache is None else cache["layers"],
-                               cfg.sliding_window)
+        x, aux, nc, st = run_stack(x, params["layers"], lora.get("layers"),
+                                   None if cache is None else cache["layers"],
+                                   cfg.sliding_window)
         aux_total += aux
         if cache is not None:
             new_cache["layers"] = nc
+        stats = {"held_rows": st["held_rows"].sum(),
+                 "max_expert_rows": st["max_expert_rows"].max()}
 
     elif fam == "hybrid":
         shared_p = params["shared_attn"]
@@ -570,7 +580,7 @@ def forward(
                 nc_m = None
             else:
                 xc, nc_m = jax.lax.scan(mbody, xc, (pp, lo_m, m_ca))
-            xo, nc_a, a = _attn_mlp_layer(
+            xo, nc_a, a, _ = _attn_mlp_layer(
                 cfg, shared_p, xc, lora=shared_lo, lora_scale=lora_scale,
                 positions=positions, window=0,
                 cache=None if ca is None else ca["attn"],
@@ -667,7 +677,7 @@ def forward(
     tied = params["embed"]["embedding"] if cfg.tie_embeddings else None
     logits = unembed(params.get("lm_head", {}), x, tied_embedding=tied,
                      lora=(lora or {}).get("lm_head"), lora_scale=lora_scale)
-    return logits, aux_total, new_cache
+    return logits, aux_total, new_cache, stats
 
 
 def _broadcast_none(n: int):
